@@ -60,14 +60,12 @@ def main():
     prog = graph.to_program()
     n_dev = len(jax.devices())
     if n_dev < pr * pc:
-        print(f"[compiled]      only {n_dev} device(s): set XLA_FLAGS="
-              f"--xla_force_host_platform_device_count={pr * pc} "
-              "for real sharding; running anyway if possible")
-    mesh = jax.sharding.Mesh(
-        np.array((jax.devices() * (pr * pc))[: pr * pc]), ("shards",)) \
-        if n_dev < pr * pc else jax.sharding.Mesh(
-            np.array(jax.devices()[: pr * pc]), ("shards",))
-    if n_dev >= pr * pc:
+        print(f"[compiled]      skipped: {pr * pc} shards need {pr * pc} "
+              f"devices, have {n_dev} (on the CPU set XLA_FLAGS="
+              f"--xla_force_host_platform_device_count={pr * pc})")
+    else:
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[: pr * pc]),
+                                 ("shards",))
         with mesh:
             run = jax.jit(cholesky_executor(prog, mesh))
             out = prog.unpack(run(jnp.asarray(prog.pack(blocks))))  # warmup

@@ -35,6 +35,7 @@ import itertools
 import os
 import socket
 import struct
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -743,6 +744,20 @@ def _merge_report(base: RecoveryReport,
     return base
 
 
+def _held_accelerator() -> Optional[str]:
+    """The non-CPU JAX platform this process has already initialised, if
+    any — read without initialising a backend. A process that has touched
+    an accelerator holds it, so a forked child that runs jnp bodies could
+    not own the device and would fail or hang on it."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return next((p for p in xla_bridge.backends() if p != "cpu"), None)
+
+
 class MultiProcBackend(Backend):
     """Fork one process per rank; rendezvous, relay, and collect."""
 
@@ -757,6 +772,13 @@ class MultiProcBackend(Backend):
                   serve_scheduler=None):
         import multiprocessing
 
+        held = _held_accelerator()
+        if held is not None:
+            raise RuntimeError(
+                f"the multiproc transport forks its ranks, but this process "
+                f"has initialised JAX on {held!r}: a forked child cannot own "
+                f"that device. Run the ranks on the inproc transport, or "
+                f"start them from a process that has not touched JAX")
         if "fork" not in multiprocessing.get_all_start_methods():
             raise RuntimeError(
                 "the multiproc transport needs the fork start method "

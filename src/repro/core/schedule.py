@@ -57,11 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.5
-except ImportError:  # pragma: no cover — older jax keeps it experimental
-    from jax.experimental.shard_map import shard_map
-
 from .discovery import (PTG, CommPattern, WavefrontSchedule, discover,
                         discover_local, segment_runs, union_pattern)
 
@@ -69,15 +64,11 @@ from .discovery import (PTG, CommPattern, WavefrontSchedule, discover,
 def _shard_map(f, *, mesh, in_specs, out_specs):
     """``shard_map`` with the replication check off: task bodies may be
     Pallas kernels (``vmap(pallas_call)`` — one fused launch per wavefront),
-    and ``pallas_call`` has no replication rule, so ``check_rep=True`` would
+    and ``pallas_call`` has no replication rule, so ``check_vma=True`` would
     reject them outright. Every executor output is sharded ``P(axis)``
-    (nothing replicated), so the check carries no information here anyway.
-    Newer jax renames/drops the flag — fall back to the plain call."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover — future jax without check_rep
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    (nothing replicated), so the check carries no information here anyway."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _narrow_tables(tree):

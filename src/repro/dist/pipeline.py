@@ -29,11 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.5
-except ImportError:  # pragma: no cover — older jax keeps it experimental
-    from jax.experimental.shard_map import shard_map
-
 from repro.core.discovery import PTG, WavefrontSchedule, segment_runs
 from repro.ptg import Graph, IndexSpace
 
@@ -157,8 +152,11 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     def run(p_local, xs_full):
         idx = jax.lax.axis_index(axis)
         p = jax.tree.map(lambda a: a[0], p_local)
-        recv = jnp.zeros(xs_full.shape[1:], xs_full.dtype)
-        outs = jnp.zeros_like(xs_full)
+        # the carries differ per stage: mark them varying over the stage
+        # axis so scan's carry types match what the stage bodies return
+        recv, outs = jax.lax.pcast(
+            (jnp.zeros(xs_full.shape[1:], xs_full.dtype),
+             jnp.zeros_like(xs_full)), (axis,), to="varying")
 
         def wavefront(w, recv, outs, perm):
             m = w - idx                       # microbatch at this stage now
@@ -188,8 +186,8 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         outs = jnp.where(idx == n_stages - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, axis)
 
-    return shard_map(run, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P())(stage_params, xs)
+    return jax.shard_map(run, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P())(stage_params, xs)
 
 
 def pipeline_loss_fn(stage_fn: Callable[[Any, jax.Array], jax.Array],

@@ -1,14 +1,17 @@
 """Public jit'd wrapper for the block GEMM kernel.
 
-On CPU (this container) the Pallas body runs in interpret mode for
-validation; on TPU it compiles to Mosaic. `matmul` auto-selects and falls
-back to the jnp oracle for shapes that do not tile cleanly.
+On the TPU the Pallas body compiles to Mosaic; on the CPU `task_matmul`
+runs it in interpret mode for validation. `matmul` auto-selects and falls
+back to the jnp oracle off the TPU and for shapes that do not tile
+cleanly.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import interpret_mode
 
 from .block_gemm import block_gemm
 from .ref import block_gemm_ref
@@ -35,7 +38,8 @@ def task_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256,
     """Per-task ``a @ b`` body for the block executor's compute step.
 
     Unlike :func:`matmul` this never falls back to the jnp oracle — it is
-    *always* the Pallas kernel (Mosaic on TPU, interpret mode elsewhere),
+    *always* the Pallas kernel (Mosaic on the TPU, interpret mode on the
+    CPU, an error on any other backend),
     so plugging it into ``gemm_bodies(matmul=task_matmul)`` /
     ``cholesky_bodies(matmul=task_matmul)`` exercises the kernel path end
     to end. The executor vmaps task bodies over each wavefront's task
@@ -44,4 +48,4 @@ def task_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256,
     kernel launch. Tile sizes clamp to the block shape, so the paper-scale
     b×b task blocks run as a single-tile grid.
     """
-    return block_gemm(a, b, bm=bm, bn=bn, bk=bk, interpret=not _on_tpu())
+    return block_gemm(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret_mode())

@@ -125,7 +125,10 @@ def main() -> None:
 
     import numpy as np
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.sched import SchedulerService
+
+    use_compile_cache()
 
     plan = None
     if args.kill or args.chaos:

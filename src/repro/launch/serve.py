@@ -33,9 +33,12 @@ def main() -> None:
     from repro.dist.sharding import (batch_axis, cache_specs, kv_head_pad,
                                      named_shardings, param_specs,
                                      sanitize_specs)
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.models import transformer as tfm
     from repro.serve.decode import make_serve_step
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -46,7 +49,7 @@ def main() -> None:
         mesh = make_production_mesh()
     else:
         model = max(1, min(4, n_dev))
-        mesh = jax.make_mesh((n_dev // model, model), ("data", "model"))
+        mesh = make_mesh((n_dev // model, model), ("data", "model"))
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}, "
           f"arch={cfg.name}")
 

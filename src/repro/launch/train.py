@@ -68,7 +68,10 @@ def main() -> None:
 
     from repro.configs.base import reduced as reduce_cfg
     from repro.configs.registry import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.train.elastic import ElasticController
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -157,7 +160,7 @@ def _run_epoch(args, cfg, seq, global_batch, devices, shape_override,
     from repro.dist.ctx import set_batch_axes, set_seq_shard, use_mesh
     from repro.dist.sharding import (batch_axis, named_shardings,
                                      param_specs, sanitize_specs)
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.train import checkpoint as ckpt
     from repro.train.data import PackedBinaryDataset, SyntheticLM
     from repro.train.elastic import StragglerDetector
@@ -181,8 +184,8 @@ def _run_epoch(args, cfg, seq, global_batch, devices, shape_override,
         if n_dev % args.pipeline:
             sys.exit(f"--pipeline {args.pipeline} does not divide "
                      f"{n_dev} devices")
-        mesh = jax.make_mesh((args.pipeline, n_dev // args.pipeline, 1),
-                             ("pipe", "data", "model"))
+        mesh = make_mesh((args.pipeline, n_dev // args.pipeline, 1),
+                         ("pipe", "data", "model"))
     elif n_dev >= 512 and args.multi_pod:
         mesh = make_production_mesh(multi_pod=True)
     elif n_dev >= 256:

@@ -396,6 +396,7 @@ def case_pipeline_train_step():
     finite metrics (the launch.train --pipeline path)."""
     from repro.configs.base import reduced
     from repro.configs.registry import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import lm_loss
     from repro.train.data import SyntheticLM
     from repro.train.train_step import (init_train_state,
@@ -403,7 +404,7 @@ def case_pipeline_train_step():
 
     _require_devices(4)
     cfg = reduced(get_config("starcoder2-3b"), n_layers=4, vocab_size=128)
-    mesh = jax.make_mesh((2, 2, 1), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 1), ("pipe", "data", "model"))
     params, opt = init_train_state(cfg, jax.random.key(0))
     ds = SyntheticLM(cfg.vocab_size, 32, 8, learnable=True)
     batch = {k: jnp.asarray(v) for k, v in ds.batch_at(0).items()}
@@ -502,6 +503,20 @@ def case_elastic_restore_smaller_mesh():
         restored = ckpt.restore(d, 7, tree, shardings=sh4)
         np.testing.assert_array_equal(np.asarray(restored["w"]), tree["w"])
         np.testing.assert_array_equal(np.asarray(restored["b"]), tree["b"])
+
+
+def case_chip_smoke_four_chips():
+    """chip_smoke.py's --four-chips phase at a tiny size on forced host
+    devices: the 2x2-sharded Cholesky matches the one-shard run and every
+    device holds its shard."""
+    import chip_smoke
+
+    _require_devices(4)
+    jax.config.update("jax_default_matmul_precision", "highest")
+    f = chip_smoke.four_chips_phase(jax.devices(), 4, 128, 0)
+    assert f["devices_holding_shards"] == 4, f
+    assert f["owned_blocks_written_on_owner"], f
+    assert f["max_rel_diff"] <= f["bound"], f
 
 
 ALL = {name[5:]: fn for name, fn in list(globals().items())

@@ -130,7 +130,7 @@ def test_cholesky_host_matches_oracle():
     "cholesky_host_matches_compiled", "pipeline_matches_sequential",
     "elastic_restore_smaller_mesh", "lowering_identity",
     "taskbench_identity", "segmented_identity", "unified_graph",
-    "pipeline_train_step", "pallas_bodies",
+    "pipeline_train_step", "pallas_bodies", "chip_smoke_four_chips",
 ])
 def test_compiled_multi_device(case):
     env = dict(os.environ,

@@ -262,6 +262,33 @@ def test_multiproc_ranks_are_real_processes():
     assert os.getpid() not in pids
 
 
+def test_multiproc_refuses_to_fork_from_a_process_holding_an_accelerator(
+        monkeypatch):
+    """A parent that has initialised JAX on an accelerator holds the device,
+    so forked ranks running jnp bodies would fail or hang on it: run_ranks
+    must refuse before it forks anything. The test stands in for an
+    initialised TPU backend by patching JAX's backend registry view."""
+    import multiprocessing
+
+    import jax  # noqa: F401 — the check looks for an imported jax
+    from jax._src import xla_bridge
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends",
+                        lambda: {"cpu": object(), "tpu": object()})
+    forked = []
+    monkeypatch.setattr(multiprocessing.context.ForkProcess, "start",
+                        lambda self: forked.append(self))
+
+    def main(ctx):
+        ctx.tp.join()
+
+    with pytest.raises(RuntimeError, match="initialised JAX on 'tpu'"):
+        run_ranks(2, main, faults=_plan(0.0), timeout=10.0,
+                  transport="multiproc")
+    assert forked == []
+
+
 # ------------------------------------------------- cross-backend bit-identity
 
 @pytest.mark.parametrize("pattern", PATTERNS)
