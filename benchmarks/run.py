@@ -18,7 +18,6 @@ Prints ``name,us_per_call,derived`` CSV. Modules:
   transport          per-comm-backend AM ping-pong latency (am_rtt_us,
                      guarded lower at the loose tol) and 1 MiB one-way
                      bandwidth, inproc threads vs multiproc OS processes
-  roofline           §Roofline (reads reports/dryrun JSONs)
 
 ``--json [PATH]`` additionally writes a ``BENCH_<utc>.json`` artifact with
 every row (plus each module's structured ``extra`` payload), so
@@ -81,7 +80,7 @@ def main() -> None:
 
     from benchmarks import (cholesky_scaling, discovery_scaling,
                             gemm_scaling, micro_deps, micro_overhead,
-                            recovery, roofline, scheduler_stream,
+                            recovery, scheduler_stream,
                             taskbench_scaling, transport)
 
     modules = {
@@ -94,7 +93,6 @@ def main() -> None:
         "recovery": recovery,
         "scheduler_stream": scheduler_stream,
         "transport": transport,
-        "roofline": roofline,
     }
     if args.only:
         modules = {k: v for k, v in modules.items() if k in args.only}

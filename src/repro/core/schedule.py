@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from . import trace
 from .discovery import (PTG, CommPattern, WavefrontSchedule, discover,
                         discover_local, segment_runs, union_pattern)
 
@@ -200,6 +201,32 @@ class BlockProgram:
 
     # ------------------------------------------------------------- stats
 
+    @property
+    def n_tasks(self) -> int:
+        """The tasks the graph has, over all shards and wavefronts."""
+        return sum(len(wf) for sh in self.schedule.shards
+                   for wf in sh.wavefronts)
+
+    def _count_lowering(self, rows: Dict[str, int], wire_slots: int,
+                        wire_blocks: int) -> None:
+        """Set the ``ttor.*`` counters for the lowering just built, per
+        solve and summed over shards: ``rows[t]`` task rows of type ``t``
+        its bodies compute (trash rows included), against ``ttor.tasks``;
+        ``wire_slots`` block slots its exchange ships (padding included),
+        against the ``wire_blocks`` that must cross."""
+        trace.gauge("ttor.tasks", self.n_tasks)
+        trace.gauge("ttor.task_rows", sum(rows.values()))
+        trace.gauge("ttor.task_rows_by_type",
+                    {t: rows.get(t, 0) for t in self.types})
+        trace.gauge("ttor.wire_slots", wire_slots)
+        trace.gauge("ttor.wire_blocks", wire_blocks)
+
+    def _count_comm(self, rows: Dict[str, int], stats: dict) -> None:
+        """``_count_lowering`` with the wire read from ``comm_stats``."""
+        waves = stats["per_wavefront"]
+        self._count_lowering(rows, sum(w["wire_blocks"] for w in waves),
+                             sum(w["real_blocks"] for w in waves))
+
     def lowered_pattern(self, w: int, comm: str = "auto",
                         density_threshold: float = 0.5) -> str:
         """The collective wavefront ``w``'s exchange lowers to under policy
@@ -338,8 +365,9 @@ class BlockProgram:
                    segmented: bool = False,
                    cover: str = "exact") -> dict:
         """Bytes on the wire per wavefront under lowering policy ``comm``
-        ("dense" | "sparse" | "auto") — feeds the roofline's collective term
-        and the §Perf iteration log.
+        ("dense" | "sparse" | "auto") — feeds the exchange counters a
+        lowering sets (``ttor.wire_slots`` / ``ttor.wire_blocks``) and the
+        §Perf iteration log.
 
         ``real_bytes`` is the payload (cross-shard data blocks, one copy per
         (src, dst) pair); ``padded_bytes`` is the *wasted* wire (trash-slot
@@ -486,7 +514,10 @@ class BlockProgram:
     # ------------------------------------------ lowering: shared building
 
     def _compute_fn(self, bodies: Dict[str, Callable[..., jnp.ndarray]]):
-        """The per-wavefront compute step shared by every lowering."""
+        """The per-wavefront compute step shared by every lowering. Its
+        device work is named by what it does: ``ttor.gather`` (operand
+        blocks by table), ``ttor.body.<type>`` (the task bodies) and
+        ``ttor.scatter`` (results back into their slots)."""
 
         def wavefront_compute(local, tbl):
             # local: [n_slots, b0, b1]; tbl[t] = (ops_idx [T, ar], out_idx [T])
@@ -494,9 +525,13 @@ class BlockProgram:
                 if t not in tbl or tbl[t][0].shape[0] == 0:
                     continue
                 ops_idx, out_idx = tbl[t]
-                ops = local[ops_idx]                 # [T, arity, b0, b1]
-                res = jax.vmap(lambda o, _t=t: bodies[_t](*jnp.unstack(o)))(ops)
-                local = local.at[out_idx].set(res.astype(local.dtype))
+                with trace.scope("ttor.gather"):
+                    ops = local[ops_idx]             # [T, arity, b0, b1]
+                with trace.scope(f"ttor.body.{t}"):
+                    res = jax.vmap(
+                        lambda o, _t=t: bodies[_t](*jnp.unstack(o)))(ops)
+                with trace.scope("ttor.scatter"):
+                    local = local.at[out_idx].set(res.astype(local.dtype))
             return local
 
         return wavefront_compute
@@ -697,6 +732,9 @@ class BlockProgram:
         """One global scan, dense all_to_all padded to global maxima."""
         wavefront_compute = self._compute_fn(bodies)
         tabs_np, M_max = self._dense_scan_tables()
+        n, W = self.spec.n_shards, len(self.tables)
+        self._count_lowering(_table_rows([tabs_np]), W * n * n * M_max,
+                             sum(p.total for p in self.patterns))
 
         def run(local, tabs):
             # local: [1, n_slots, b0, b1]; tabs: {k: [1, W, ...]}
@@ -708,11 +746,12 @@ class BlockProgram:
                        for t in self.types if f"{t}:ops" in wtab}
                 loc0 = wavefront_compute(loc0, tbl)
                 if M_max:
-                    buf = loc0[wtab["send"]]         # [n, M, b0, b1]
-                    buf = jax.lax.all_to_all(buf, axis, split_axis=0,
-                                             concat_axis=0, tiled=True)
-                    loc0 = loc0.at[wtab["recv"].reshape(-1)].set(
-                        buf.reshape(-1, *loc0.shape[1:]))
+                    with trace.scope("ttor.exchange"):
+                        buf = loc0[wtab["send"]]     # [n, M, b0, b1]
+                        buf = jax.lax.all_to_all(buf, axis, split_axis=0,
+                                                 concat_axis=0, tiled=True)
+                        loc0 = loc0.at[wtab["recv"].reshape(-1)].set(
+                            buf.reshape(-1, *loc0.shape[1:]))
                 return loc0[None], None
 
             local, _ = jax.lax.scan(step, local, tabs0)
@@ -741,12 +780,17 @@ class BlockProgram:
         segment plan — only the (static) perms and the table contents
         change, never the scan-body structure."""
         segs = self._segment_tables(comm, density_threshold, overlap, cover)
+        self._count_comm(
+            _table_rows([tabs for *_, tabs in segs]),
+            self.comm_stats(comm=comm, density_threshold=density_threshold,
+                            segmented=True, cover=cover))
         wavefront_compute = self._compute_fn(bodies)
 
         def tbl_of(wtab, prefix=""):
             return {t: (wtab[f"{t}:{prefix}ops"], wtab[f"{t}:{prefix}out"])
                     for t in self.types if f"{t}:{prefix}ops" in wtab}
 
+        @trace.scope("ttor.exchange")
         def seg_issue(loc0, rows, sig):
             """Issue one wavefront's exchange from segment-padded tables;
             returns the in-flight buffers (the scan-carry pytree)."""
@@ -761,6 +805,7 @@ class BlockProgram:
                     for r, perm in enumerate(sig[1]))
             return ()
 
+        @trace.scope("ttor.exchange")
         def seg_land(loc0, rows, sig, bufs):
             if sig[0] == "all_to_all":
                 return loc0.at[rows["recv"].reshape(-1)].set(
@@ -854,7 +899,16 @@ class BlockProgram:
         W = len(self.tables)
         choices = [self.lowered_pattern(w, comm, density_threshold)
                    for w in range(W)]
+        # with overlap, wavefront w runs its tables split around the
+        # landing of w - 1's exchange (unsplit where nothing lands)
+        used = [self._split_tables(w) if overlap else (self.tables[w],)
+                for w in range(W)]
+        self._count_comm(
+            _table_rows([{f"{t}:ops": o for t, (o, _) in tbl.items()}
+                         for parts in used for tbl in parts if tbl]),
+            self.comm_stats(comm=comm, density_threshold=density_threshold))
 
+        @trace.scope("ttor.exchange")
         def issue(loc0, idx, w):
             if choices[w] == "none":
                 return []
@@ -872,6 +926,7 @@ class BlockProgram:
                 pending.append((_narrow_tables(rnd.recv)[idx], buf))
             return pending
 
+        @trace.scope("ttor.exchange")
         def land(loc0, pending):
             for recv, buf in pending:
                 loc0 = loc0.at[recv].set(buf.astype(loc0.dtype))
@@ -1041,6 +1096,17 @@ class BlockProgram:
         return self.executor(bodies, mesh, axis, scan=True, comm="dense")
 
 
+def _table_rows(tabs_list) -> Dict[str, int]:
+    """Task rows per type in compute tables keyed ``[h:]<type>:[i|d]ops``,
+    each ``[..., T, arity]``: every axis but the arity counts rows."""
+    rows: Dict[str, int] = defaultdict(int)
+    for tabs in tabs_list:
+        for key, ops in tabs.items():
+            if key.endswith("ops"):
+                rows[key.split(":")[-2]] += int(np.prod(ops.shape[:-1]))
+    return rows
+
+
 def build_block_program(spec: BlockPTGSpec, *,
                         validate: bool = False) -> BlockProgram:
     """Discover the schedule and build all index tables (host side, numpy).
@@ -1057,10 +1123,11 @@ def build_block_program(spec: BlockPTGSpec, *,
     recommended for hand-written specs; :mod:`repro.ptg` graphs carry the
     guarantee by construction."""
     ptg, n = spec.ptg, spec.n_shards
-    if spec.views is not None:
-        sched = discover_local(spec.views, n, validate=validate)
-    else:
-        sched = discover(ptg, spec.seeds, n, validate=validate)
+    with trace.span("ttor.discover"):
+        if spec.views is not None:
+            sched = discover_local(spec.views, n, validate=validate)
+        else:
+            sched = discover(ptg, spec.seeds, n, validate=validate)
     sched.validate(ptg)
 
     # --- slot assignment: owned blocks first, then halo copies, then trash.

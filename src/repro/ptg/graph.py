@@ -59,6 +59,7 @@ from typing import (Callable, Dict, Hashable, Iterable, List, Optional,
 
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.core.discovery import (PTG, WavefrontSchedule, discover,
                                   discover_local, union_ptg)
 
@@ -654,11 +655,13 @@ class Graph:
         them here is the single-host emulation of that, and the per-view
         ``stats`` are what the distributed ranks would each pay."""
         if owner_map is not None:
-            return [self.derive_local(s, owner_map)
-                    for s in range(self.n_shards)]
+            with trace.span("ttor.derive"):
+                return [self.derive_local(s, owner_map)
+                        for s in range(self.n_shards)]
         if self._views is None:
-            self._views = [self.derive_local(s)
-                           for s in range(self.n_shards)]
+            with trace.span("ttor.derive"):
+                self._views = [self.derive_local(s)
+                               for s in range(self.n_shards)]
         return self._views
 
     # ---------------------------------------------------- derived queries

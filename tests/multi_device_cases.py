@@ -519,6 +519,44 @@ def case_chip_smoke_four_chips():
     assert f["max_rel_diff"] <= f["bound"], f
 
 
+def case_trace_scopes():
+    """The 2x2 union-cover scan: every operation of the executor's work sits
+    under a ``ttor.`` scope, the exchange's among them; and the counters its
+    lowering sets equal a recount from the tables and ``comm_stats``."""
+    from collections import Counter
+
+    from repro.core import trace
+    from repro.linalg.cholesky import cholesky_executor, cholesky_graph
+    from tests.test_trace import scoped_hlo_case
+
+    mesh = _mesh(4)
+    prog = cholesky_graph(4, 2, 2, 128).to_program()
+    for overlap in (True, False):
+        plan = prog.plan_lowering(unroll_cap=2, overlap=overlap)
+        assert plan["mode"] == "union_cover", plan
+    found = scoped_hlo_case(4, 2, 2, 128, mesh, unroll_cap=2)
+    assert "ttor.exchange" in found, found
+
+    # without overlap, each union segment runs every wavefront's tables
+    # padded to the segment's longest, on every shard
+    trace.reset()
+    cholesky_executor(prog, mesh, unroll_cap=2, overlap=False)
+    got = trace.snapshot()["gauges"]
+    rows = Counter()
+    for s, e in prog.segments(comm="auto", cover="union"):
+        for t in prog.types:
+            longest = max((prog.tables[w][t][0].shape[1] for w in range(s, e)
+                           if t in prog.tables[w]), default=0)
+            rows[t] += prog.spec.n_shards * (e - s) * longest
+    assert got["ttor.task_rows_by_type"] == dict(rows), got
+    assert got["ttor.task_rows"] == sum(rows.values())
+    stats = prog.comm_stats(comm="auto", segmented=True, cover="union")
+    assert got["ttor.wire_slots"] == sum(
+        w["wire_blocks"] for w in stats["per_wavefront"])
+    assert got["ttor.wire_blocks"] == sum(p.total for p in prog.patterns) > 0
+    assert got["ttor.wire_slots"] > got["ttor.wire_blocks"]
+
+
 ALL = {name[5:]: fn for name, fn in list(globals().items())
        if name.startswith("case_")}
 

@@ -12,6 +12,7 @@ task bodies to their compiled (not interpreted) form themselves.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -90,5 +91,10 @@ def test_cholesky_executor_compiles_for_v5e(topo, compiled_bodies, grid):
                                     matmul=gemm_ops.task_matmul))
     text = run.lower(x).compile().as_text()
     assert "tpu_custom_call" in text
+    # the kernel's custom call keeps the name the benchmark finds it by
+    # (``block_gemm``), under the task body's scope
+    assert re.search(r"%\S*block_gemm\S* = \S+ custom-call\(", text)
+    assert "ttor.body.gemm" in text
     if pr * pc > 1:
         assert "collective-permute" in text or "all-to-all" in text
+        assert "ttor.exchange" in text
