@@ -207,24 +207,30 @@ class BlockProgram:
         return sum(len(wf) for sh in self.schedule.shards
                    for wf in sh.wavefronts)
 
-    def _count_lowering(self, rows: Dict[str, int], wire_slots: int,
+    def _count_lowering(self, bodies, rows: Dict[str, int], wire_slots: int,
                         wire_blocks: int) -> None:
         """Set the ``ttor.*`` counters for the lowering just built, per
         solve and summed over shards: ``rows[t]`` task rows of type ``t``
         its bodies compute (trash rows included), against ``ttor.tasks``;
-        ``wire_slots`` block slots its exchange ships (padding included),
-        against the ``wire_blocks`` that must cross."""
+        ``ttor.indexed_rows``, those of them whose body reads its operands
+        from the slot array by index (``from_slots``); ``wire_slots`` block
+        slots its exchange ships (padding included), against the
+        ``wire_blocks`` that must cross."""
         trace.gauge("ttor.tasks", self.n_tasks)
         trace.gauge("ttor.task_rows", sum(rows.values()))
         trace.gauge("ttor.task_rows_by_type",
                     {t: rows.get(t, 0) for t in self.types})
+        trace.gauge("ttor.indexed_rows",
+                    sum(n for t, n in rows.items()
+                        if hasattr(bodies.get(t), "from_slots")))
         trace.gauge("ttor.wire_slots", wire_slots)
         trace.gauge("ttor.wire_blocks", wire_blocks)
 
-    def _count_comm(self, rows: Dict[str, int], stats: dict) -> None:
+    def _count_comm(self, bodies, rows: Dict[str, int], stats: dict) -> None:
         """``_count_lowering`` with the wire read from ``comm_stats``."""
         waves = stats["per_wavefront"]
-        self._count_lowering(rows, sum(w["wire_blocks"] for w in waves),
+        self._count_lowering(bodies, rows,
+                             sum(w["wire_blocks"] for w in waves),
                              sum(w["real_blocks"] for w in waves))
 
     def lowered_pattern(self, w: int, comm: str = "auto",
@@ -517,7 +523,9 @@ class BlockProgram:
         """The per-wavefront compute step shared by every lowering. Its
         device work is named by what it does: ``ttor.gather`` (operand
         blocks by table), ``ttor.body.<type>`` (the task bodies) and
-        ``ttor.scatter`` (results back into their slots)."""
+        ``ttor.scatter`` (results back into their slots). A body with a
+        ``from_slots(local, ops_idx) -> [T, b0, b1]`` form reads its
+        operands from the slot array itself, and is given no gather."""
 
         def wavefront_compute(local, tbl):
             # local: [n_slots, b0, b1]; tbl[t] = (ops_idx [T, ar], out_idx [T])
@@ -525,11 +533,15 @@ class BlockProgram:
                 if t not in tbl or tbl[t][0].shape[0] == 0:
                     continue
                 ops_idx, out_idx = tbl[t]
-                with trace.scope("ttor.gather"):
-                    ops = local[ops_idx]             # [T, arity, b0, b1]
-                with trace.scope(f"ttor.body.{t}"):
-                    res = jax.vmap(
-                        lambda o, _t=t: bodies[_t](*jnp.unstack(o)))(ops)
+                if hasattr(bodies[t], "from_slots"):
+                    with trace.scope(f"ttor.body.{t}"):
+                        res = bodies[t].from_slots(local, ops_idx)
+                else:
+                    with trace.scope("ttor.gather"):
+                        ops = local[ops_idx]         # [T, arity, b0, b1]
+                    with trace.scope(f"ttor.body.{t}"):
+                        res = jax.vmap(
+                            lambda o, _t=t: bodies[_t](*jnp.unstack(o)))(ops)
                 with trace.scope("ttor.scatter"):
                     local = local.at[out_idx].set(res.astype(local.dtype))
             return local
@@ -733,7 +745,8 @@ class BlockProgram:
         wavefront_compute = self._compute_fn(bodies)
         tabs_np, M_max = self._dense_scan_tables()
         n, W = self.spec.n_shards, len(self.tables)
-        self._count_lowering(_table_rows([tabs_np]), W * n * n * M_max,
+        self._count_lowering(bodies, _table_rows([tabs_np]),
+                             W * n * n * M_max,
                              sum(p.total for p in self.patterns))
 
         def run(local, tabs):
@@ -781,7 +794,7 @@ class BlockProgram:
         change, never the scan-body structure."""
         segs = self._segment_tables(comm, density_threshold, overlap, cover)
         self._count_comm(
-            _table_rows([tabs for *_, tabs in segs]),
+            bodies, _table_rows([tabs for *_, tabs in segs]),
             self.comm_stats(comm=comm, density_threshold=density_threshold,
                             segmented=True, cover=cover))
         wavefront_compute = self._compute_fn(bodies)
@@ -904,7 +917,7 @@ class BlockProgram:
         used = [self._split_tables(w) if overlap else (self.tables[w],)
                 for w in range(W)]
         self._count_comm(
-            _table_rows([{f"{t}:ops": o for t, (o, _) in tbl.items()}
+            bodies, _table_rows([{f"{t}:ops": o for t, (o, _) in tbl.items()}
                          for parts in used for tbl in parts if tbl]),
             self.comm_stats(comm=comm, density_threshold=density_threshold))
 

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
 
-from .block_gemm import block_gemm
+from .block_gemm import block_gemm, block_gemm_indexed
 from .ref import block_gemm_ref
 
 
@@ -47,5 +47,20 @@ def task_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256,
     dimension: all of a wavefront's trailing updates become one fused
     kernel launch. Tile sizes clamp to the block shape, so the paper-scale
     b×b task blocks run as a single-tile grid.
+
+    ``task_matmul.indexed_update`` is :func:`task_update`: the same kernel
+    in the form that reads a trailing update's blocks from the slot array.
     """
     return block_gemm(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret_mode())
+
+
+def task_update(local: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """``out[t] = local[c] - local[a] @ local[b].T`` for each row (c, a, b)
+    of ``table`` ``[T, 3]``: a wavefront's trailing updates as one kernel
+    launch that reads its operand blocks where they lie in the executor's
+    slot array ``local`` ``[n_slots, b, b]``, instead of from a gathered
+    ``[T, 3, b, b]`` copy. Always the Pallas kernel, as ``task_matmul``."""
+    return block_gemm_indexed(local, table, interpret=interpret_mode())
+
+
+task_matmul.indexed_update = task_update

@@ -134,7 +134,13 @@ def cholesky_executor(prog: BlockProgram, mesh, axis: str = "shards", *,
 
 
 def cholesky_bodies(matmul=None, trsm=None) -> Dict[str, object]:
-    """Per-block bodies; matmul/trsm pluggable (jnp fallback or Pallas)."""
+    """Per-block bodies; matmul/trsm pluggable (jnp fallback or Pallas).
+
+    Where ``matmul`` has an ``indexed_update(local, table)`` (``out[t] =
+    local[c] - local[a] @ local[b].T`` for rows (c, a, b), as
+    ``task_matmul`` has), ``syrk`` and ``gemm`` carry it as ``from_slots
+    (local, ops_idx)``: the executor then hands them the slot array and
+    their operand columns, and gathers nothing for them."""
     mm = matmul if matmul is not None else lambda a, b: a @ b
 
     def _trsm(a, l_kk):
@@ -142,11 +148,24 @@ def cholesky_bodies(matmul=None, trsm=None) -> Dict[str, object]:
         return jax.scipy.linalg.solve_triangular(
             l_kk, a.T, lower=True, trans="N").T
 
+    def syrk(a, l):
+        return a - mm(l, l.T)
+
+    def gemm(a, li, lj):
+        return a - mm(li, lj.T)
+
+    update = getattr(matmul, "indexed_update", None)
+    if update is not None:
+        # syrk's columns (A_ii, L_ik) as the update's (c, a, b) = (A, L, L)
+        syrk.from_slots = lambda local, ops: update(
+            local, jnp.concatenate([ops, ops[:, 1:]], axis=1))
+        gemm.from_slots = update            # (A_ij, L_ik, L_jk) already
+
     return {
         "potrf": lambda a: jnp.linalg.cholesky(a),
         "trsm": trsm if trsm is not None else _trsm,
-        "syrk": lambda a, l: a - mm(l, l.T),
-        "gemm": lambda a, li, lj: a - mm(li, lj.T),
+        "syrk": syrk,
+        "gemm": gemm,
     }
 
 

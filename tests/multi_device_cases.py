@@ -557,6 +557,46 @@ def case_trace_scopes():
     assert got["ttor.wire_slots"] > got["ttor.wire_blocks"]
 
 
+def case_indexed_bodies():
+    """Four shards, every lowering: Cholesky whose syrk and gemm read their
+    operands from the slot array by index (``task_matmul``'s indexed form,
+    interpreted) matches the jnp bodies within float32 rounding, and the
+    lowering counts exactly those rows as ``ttor.indexed_rows``."""
+    from repro.core import trace
+    from repro.kernels.block_gemm.ops import task_matmul
+    from repro.linalg.cholesky import (assemble_lower, cholesky_bodies,
+                                       cholesky_graph, make_spd_blocks)
+
+    nb, pr, pc, b = 6, 2, 2, 8
+    prog = cholesky_graph(nb, pr, pc, b).to_program()
+    blocks, _ = make_spd_blocks(nb, b)
+    packed = jnp.asarray(prog.pack(blocks))
+    mesh = _mesh(pr * pc)
+    variants = (
+        dict(scan=False),
+        dict(scan=False, overlap=True),
+        dict(scan=True),
+        dict(scan=True, comm="auto"),
+        dict(scan=True, comm="auto", overlap=True),
+        dict(scan=True, comm="auto", cover="union"),
+        dict(scan=True, comm="auto", overlap=True, cover="union"),
+    )
+    with mesh:
+        want = assemble_lower(prog.unpack(jax.jit(prog.executor(
+            cholesky_bodies(), mesh, scan=False))(packed)), nb, b)
+        for kw in variants:
+            trace.reset()
+            run = jax.jit(prog.executor(cholesky_bodies(task_matmul), mesh,
+                                        **kw))
+            rows = trace.snapshot()["gauges"]
+            got = assemble_lower(prog.unpack(run(packed)), nb, b)
+            np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5,
+                                       err_msg=f"{kw}")
+            by_type = rows["ttor.task_rows_by_type"]
+            assert (rows["ttor.indexed_rows"]
+                    == by_type["syrk"] + by_type["gemm"] > 0), (kw, rows)
+
+
 ALL = {name[5:]: fn for name, fn in list(globals().items())
        if name.startswith("case_")}
 

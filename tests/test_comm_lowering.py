@@ -13,9 +13,14 @@ a brute-force walk of the PTG's cross-shard edges:
 
 (Bit-identity of the sparse/overlap executors vs the unrolled dense
 reference runs on 8 emulated devices in ``tests/multi_device_cases.py`` —
-cases ``lowering_identity`` and ``taskbench_identity``.)
+cases ``lowering_identity`` and ``taskbench_identity``; the trailing updates
+read by index on four shards, in every lowering, run there as case
+``indexed_bodies``.)
 """
 
+import os
+import subprocess
+import sys
 from collections import defaultdict
 
 import numpy as np
@@ -152,3 +157,19 @@ def test_halo_split_partitions_wavefronts(n_layers, width, n_shards,
             assert sorted(map(repr, indep + dep)) == sorted(map(repr, tasks))
             assert all(k in arriving for k in dep)
             assert all(k not in arriving for k in indep)
+
+
+def test_indexed_bodies_on_four_shards():
+    """The exchange lowerings with trailing updates that read the slot
+    array by index: every lowering on four emulated devices, in a child
+    process (``tests.multi_device_cases indexed_bodies``)."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    proc = subprocess.run(
+        [sys.executable, "-m", "tests.multi_device_cases", "indexed_bodies"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, \
+        f"\nstdout:{proc.stdout}\nstderr:{proc.stderr}"
+    assert "CASE indexed_bodies OK" in proc.stdout, proc.stdout

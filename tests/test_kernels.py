@@ -7,7 +7,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.block_gemm.block_gemm import block_gemm
+from repro.kernels.block_gemm.block_gemm import (block_gemm,
+                                                 block_gemm_indexed)
 from repro.kernels.block_gemm.ref import block_gemm_ref
 from repro.kernels.decode_attention.decode_attention import decode_attention
 from repro.kernels.decode_attention.ref import decode_ref
@@ -43,6 +44,71 @@ def test_block_gemm_sweep(m, n, k, bm, bn, bk, dtype):
     want = block_gemm_ref(a, b)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+def _update_ref(local, table):
+    """local[c] - local[a] @ local[b]^T per row (c, a, b), in float64."""
+    x = np.asarray(local, np.float64)
+    c, a, b = np.asarray(table).T
+    return x[c] - np.einsum("tik,tjk->tij", x[a], x[b])
+
+
+@pytest.mark.parametrize("b,tiles", [
+    (128, (64, 64, 64)),          # 2 x 2 output tiles, 2 K steps each
+    (128, (128, 128, 128)),       # one tile, one step per row
+    (256, (128, 128, 64)),
+    (256, (256, 256, 256)),
+], ids=["b128_t64", "b128_t128", "b256_t128x64", "b256_t256"])
+def test_block_gemm_indexed_matches_the_update(b, tiles):
+    """Each row of the table reads its three blocks from the slot array by
+    index: out[t] = local[c] - local[a] @ local[b]^T, in float32."""
+    rng = np.random.default_rng(b + tiles[2])
+    local = jnp.asarray(rng.standard_normal((9, b, b)) / np.sqrt(b),
+                        jnp.float32)
+    table = rng.integers(0, 9, (5, 3)).astype(np.int32)
+    bm, bn, bk = tiles
+    got = block_gemm_indexed(local, jnp.asarray(table), bm=bm, bn=bn, bk=bk,
+                             interpret=True)
+    assert got.shape == (5, b, b) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, _update_ref(local, table),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("table", [
+    [[4, 1, 1], [5, 2, 2]],            # syrk's rows: A - L L^T, L twice
+    [[6, 6, 6], [0, 2, 3], [6, 6, 6]],  # padding rows on the trash slot
+    [[3, 0, 1]],                       # T = 1
+], ids=["repeated", "trash", "one_row"])
+def test_block_gemm_indexed_tables(table):
+    """Repeated operands, padding rows that all point at the trash slot (the
+    last one) and computed like any other, and a one-row table."""
+    rng = np.random.default_rng(len(table))
+    local = jnp.asarray(rng.standard_normal((7, 128, 128)) / np.sqrt(128),
+                        jnp.float32)
+    table = np.asarray(table, np.int16)       # the executor's narrow tables
+    got = block_gemm_indexed(local, jnp.asarray(table), bm=64, bn=64, bk=64,
+                             interpret=True)
+    np.testing.assert_allclose(got, _update_ref(local, table),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("body", ["syrk", "gemm"])
+def test_cholesky_update_bodies_at_b768(body):
+    """A block width that the 512-wide default tiles do not divide still
+    runs (768 takes 256-wide tiles), through the indexed form the Cholesky
+    bodies carry with ``task_matmul``."""
+    from repro.kernels.block_gemm.ops import task_matmul
+    from repro.linalg.cholesky import cholesky_bodies
+    rng = np.random.default_rng(768)
+    local = jnp.asarray(rng.standard_normal((4, 768, 768)) / np.sqrt(768),
+                        jnp.float32)
+    ops = np.asarray({"syrk": [[3, 0], [2, 1]],
+                      "gemm": [[3, 0, 1], [2, 1, 0]]}[body], np.int32)
+    got = cholesky_bodies(task_matmul)[body].from_slots(local,
+                                                        jnp.asarray(ops))
+    rows = ops if body == "gemm" else np.concatenate([ops, ops[:, 1:]], 1)
+    np.testing.assert_allclose(got, _update_ref(local, rows),
+                               rtol=2e-5, atol=2e-5)
 
 
 # -------------------------------------------------------- flash_attention

@@ -8,6 +8,11 @@ block-PTGs — bit-identity of the segmented executors vs the unrolled and
 dense-scan references runs on 8 emulated devices in
 ``tests/multi_device_cases.py`` (cases ``lowering_identity`` and
 ``segmented_identity``).
+
+Also the trailing updates that read their operands from the slot array by
+index (``from_slots``) in every lowering: what they count, what they compute
+on one shard, and the gathers the other bodies keep (four shards:
+``tests/multi_device_cases.py``, case ``indexed_bodies``).
 """
 
 import logging
@@ -19,8 +24,13 @@ from hypothesis import HealthCheck, given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.core.discovery import segment_runs
 from repro.core.schedule import build_block_program
+from repro.kernels.block_gemm.ops import task_matmul
+from repro.linalg.cholesky import (assemble_lower, cholesky_bodies,
+                                   cholesky_graph, make_spd_blocks)
+from repro.linalg.gemm import gemm_2d_graph, gemm_bodies
 
 from tests.test_schedule_property import random_layered_ptg
 
@@ -241,3 +251,91 @@ def test_executor_rejects_unknown_comm():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("shards",))
     with pytest.raises(ValueError, match="unknown comm policy"):
         prog.executor({}, mesh, comm="bogus")
+
+
+# ------------------------------------ trailing updates read from the slots
+
+# every lowering ``BlockProgram.executor`` builds, by its arguments
+LOWERINGS = {
+    "unrolled": dict(scan=False),
+    "unrolled_overlap": dict(scan=False, overlap=True),
+    "dense_scan": dict(scan=True),
+    "segmented_scan": dict(scan=True, comm="auto"),
+    "segmented_scan_overlap": dict(scan=True, comm="auto", overlap=True),
+    "union_cover": dict(scan=True, comm="auto", cover="union"),
+    "union_cover_overlap": dict(scan=True, comm="auto", overlap=True,
+                                cover="union"),
+}
+
+
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2)], ids=["1shard", "2x2"])
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_indexed_rows_are_the_trailing_updates(lowering, grid):
+    """With ``task_matmul`` the syrk and gemm bodies read their operands by
+    index: ``ttor.indexed_rows`` is their rows of the lowering, trash rows
+    included. The jnp bodies and GEMM's ``c + a b`` have no indexed form
+    and count none. (Lowering builds the executor; nothing is traced.)"""
+    trace.reset()
+    kw = LOWERINGS[lowering]
+    mesh = jax.sharding.AbstractMesh((grid[0] * grid[1],), ("shards",))
+    prog = cholesky_graph(6, *grid, 8).to_program()
+    for matmul, indexed in ((task_matmul, ("syrk", "gemm")), (None, ())):
+        prog.executor(cholesky_bodies(matmul), mesh, **kw)
+        got = trace.snapshot()["gauges"]
+        rows = got["ttor.task_rows_by_type"]
+        assert got["ttor.indexed_rows"] == sum(rows[t] for t in indexed)
+        assert rows["gemm"] > 0 and rows["syrk"] > 0
+    gemm = gemm_2d_graph(4, *grid, 8, staged=True).to_program()
+    gemm.executor(gemm_bodies(task_matmul), mesh, **kw)
+    got = trace.snapshot()["gauges"]
+    assert got["ttor.indexed_rows"] == 0 < got["ttor.task_rows"]
+    trace.reset()
+
+
+@pytest.mark.parametrize("lowering", ["unrolled", "dense_scan",
+                                      "segmented_scan",
+                                      "segmented_scan_overlap"])
+def test_indexed_updates_match_the_jnp_bodies(lowering):
+    """One shard, the Pallas kernel interpreted: the factor from syrk and
+    gemm read by index equals the jnp bodies' within float32 rounding.
+    (The union cover is the segmented scan on one shard; four shards run
+    every lowering in ``multi_device_cases indexed_bodies``.)"""
+    nb, b = 5, 16
+    prog = cholesky_graph(nb, 1, 1, b).to_program()
+    blocks, a = make_spd_blocks(nb, b)
+    packed = jnp.asarray(prog.pack(blocks))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("shards",))
+
+    def factor(matmul):
+        run = jax.jit(prog.executor(cholesky_bodies(matmul), mesh,
+                                    **LOWERINGS[lowering]))
+        return assemble_lower(prog.unpack(run(packed)), nb, b)
+
+    got = factor(task_matmul)
+    np.testing.assert_allclose(got, factor(None), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got, np.linalg.cholesky(a),
+                               rtol=5e-3, atol=5e-3)
+
+
+def _gathers(prog, bodies) -> int:
+    """Gather operations in the unrolled one-shard executor's StableHLO."""
+    b0, b1 = prog.spec.block_shape
+    x = jax.ShapeDtypeStruct((1, prog.n_slots, b0, b1), jnp.float32)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("shards",))
+    run = jax.jit(prog.executor(bodies, mesh, scan=False))
+    return run.lower(x).as_text().count('"stablehlo.gather"(')
+
+
+def test_bodies_without_an_indexed_form_keep_their_gather():
+    """Unrolled, each (wavefront, type) computed gathers its operands once,
+    except where the body reads them by index: the jnp Cholesky bodies and
+    GEMM's keep every gather, ``task_matmul``'s syrk and gemm drop theirs."""
+    prog = cholesky_graph(5, 1, 1, 16).to_program()
+    calls = {t: sum(t in tbl for tbl in prog.tables) for t in prog.types}
+    assert _gathers(prog, cholesky_bodies()) == sum(calls.values())
+    assert (_gathers(prog, cholesky_bodies(task_matmul))
+            == calls["potrf"] + calls["trsm"])
+    gemm = gemm_2d_graph(2, 1, 1, 16, staged=True).to_program()
+    n_calls = sum(len(tbl) for tbl in gemm.tables)
+    assert (_gathers(gemm, gemm_bodies(task_matmul))
+            == _gathers(gemm, gemm_bodies()) == n_calls)

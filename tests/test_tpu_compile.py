@@ -21,8 +21,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from bench.devtrace import parse_instruction
+from bench.families.cholesky import is_kernel, kernel_cost
 from repro.kernels.block_gemm import ops as gemm_ops
-from repro.kernels.block_gemm.block_gemm import block_gemm
+from repro.kernels.block_gemm.block_gemm import block_gemm, block_gemm_indexed
 from repro.kernels.flash_attention import ops as attn_ops
 from repro.linalg.cholesky import cholesky_executor, cholesky_graph
 
@@ -70,6 +72,20 @@ def test_vmapped_block_gemm_compiles_for_v5e(topo):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("b", [768, 1024])
+def test_indexed_block_gemm_compiles_for_v5e(topo, b):
+    """The indexed trailing update at its default 512-wide tiles (b = 1024)
+    and at the 256-wide tiles they halve to where 512 does not divide the
+    block (b = 768), reading from a slot array of ten blocks."""
+    local = _on_one_chip(topo, (10, b, b))
+    table = jax.ShapeDtypeStruct((6, 3), jnp.int32,
+                                 sharding=local.sharding)
+    run = jax.jit(lambda x, t: block_gemm_indexed(x, t, interpret=False))
+    with jax.default_matmul_precision("highest"):
+        text = run.lower(local, table).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
 def test_task_attention_compiles_for_v5e(topo, compiled_bodies):
     """The attention task body at a real head width (128) and 1024 rows."""
     q = _on_one_chip(topo, (1024, 128))
@@ -95,6 +111,21 @@ def test_cholesky_executor_compiles_for_v5e(topo, compiled_bodies, grid):
     # (``block_gemm``), under the task body's scope
     assert re.search(r"%\S*block_gemm\S* = \S+ custom-call\(", text)
     assert "ttor.body.gemm" in text
+    # syrk and gemm read their blocks from the slot array: each kernel call
+    # takes the whole [n_slots, b, b] state first and writes [T, b, b], so
+    # the benchmark counts 2 T b^3 flops for it; no [T, 3, b, b] operand
+    # stack is built for gemm any more
+    calls = [op for op in map(parse_instruction, text.splitlines())
+             if op is not None and is_kernel(op)]
+    assert calls
+    for op in calls:
+        assert op.operand_shapes[0] == (prog.n_slots, b, b), op
+        n_rows = op.shape[0]
+        assert op.shape == (n_rows, b, b), op
+        flops, _ = kernel_cost(op.shape, op.operand_shapes)
+        assert flops == 2 * n_rows * b ** 3
+    gemm_rows = max(op.shape[0] for op in calls)
+    assert f"f32[{gemm_rows},3,{b},{b}]" not in text
     if pr * pc > 1:
         assert "collective-permute" in text or "all-to-all" in text
         assert "ttor.exchange" in text
