@@ -213,16 +213,21 @@ class BlockProgram:
         solve and summed over shards: ``rows[t]`` task rows of type ``t``
         its bodies compute (trash rows included), against ``ttor.tasks``;
         ``ttor.indexed_rows``, those of them whose body reads its operands
-        from the slot array by index (``from_slots``); ``wire_slots`` block
-        slots its exchange ships (padding included), against the
-        ``wire_blocks`` that must cross."""
+        from the slot array by index (``from_slots``); ``ttor.gather_bytes``,
+        the bytes the operand gathers of the others copy (each row's
+        ``arity`` blocks); ``wire_slots`` block slots its exchange ships
+        (padding included), against the ``wire_blocks`` that must cross."""
+        indexed = {t for t in rows if hasattr(bodies.get(t), "from_slots")}
+        block_bytes = (int(np.prod(self.spec.block_shape))
+                       * jnp.dtype(self.spec.dtype).itemsize)
         trace.gauge("ttor.tasks", self.n_tasks)
         trace.gauge("ttor.task_rows", sum(rows.values()))
         trace.gauge("ttor.task_rows_by_type",
                     {t: rows.get(t, 0) for t in self.types})
-        trace.gauge("ttor.indexed_rows",
-                    sum(n for t, n in rows.items()
-                        if hasattr(bodies.get(t), "from_slots")))
+        trace.gauge("ttor.indexed_rows", sum(rows[t] for t in indexed))
+        trace.gauge("ttor.gather_bytes",
+                    sum(n * self.arity[t] * block_bytes
+                        for t, n in rows.items() if t not in indexed))
         trace.gauge("ttor.wire_slots", wire_slots)
         trace.gauge("ttor.wire_blocks", wire_blocks)
 

@@ -22,11 +22,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bench.devtrace import parse_instruction
+from bench.families import gemm as gemm_family
 from bench.families.cholesky import is_kernel, kernel_cost
 from repro.kernels.block_gemm import ops as gemm_ops
 from repro.kernels.block_gemm.block_gemm import block_gemm, block_gemm_indexed
 from repro.kernels.flash_attention import ops as attn_ops
 from repro.linalg.cholesky import cholesky_executor, cholesky_graph
+from repro.linalg.gemm import gemm_2d_graph, gemm_executor
 
 
 @pytest.fixture(scope="module")
@@ -129,3 +131,22 @@ def test_cholesky_executor_compiles_for_v5e(topo, compiled_bodies, grid):
     if pr * pc > 1:
         assert "collective-permute" in text or "all-to-all" in text
         assert "ttor.exchange" in text
+
+
+def test_gemm_executor_compiles_for_v5e(topo, compiled_bodies):
+    """The GEMM executor on one chip: every gemm body gathers its operands
+    and runs the vmapped kernel, whose calls the GEMM family finds and
+    costs at 2 N^3 flops in all (the unrolled rows carry no padding)."""
+    nb, b = 4, 512
+    prog = gemm_2d_graph(nb, 1, 1, b).to_program()
+    mesh = Mesh(np.array(topo.devices[:1]), ("shards",))
+    x = jax.ShapeDtypeStruct((1, prog.n_slots, b, b), jnp.float32,
+                             sharding=NamedSharding(mesh, P("shards")))
+    run = jax.jit(gemm_executor(prog, mesh, matmul=gemm_ops.task_matmul))
+    text = run.lower(x).compile().as_text()
+    assert "ttor.gather" in text and "ttor.body.gemm" in text
+    calls = [op for op in map(parse_instruction, text.splitlines())
+             if op is not None and gemm_family.is_kernel(op)]
+    assert len(calls) == nb
+    assert sum(gemm_family.kernel_cost(op.shape, op.operand_shapes)[0]
+               for op in calls) == 2 * (nb * b) ** 3
